@@ -144,9 +144,9 @@ def bench_flash_decode_paged(rng, tiny):
         H = KV * G
         npg = P // B
         pool_k = jnp.asarray(
-            rng.standard_normal((L, P + 1, ps, KV, hd)), jnp.float32)
+            rng.standard_normal((L, P + 1, KV, ps, hd)), jnp.float32)
         pool_v = jnp.asarray(
-            rng.standard_normal((L, P + 1, ps, KV, hd)), jnp.float32)
+            rng.standard_normal((L, P + 1, KV, ps, hd)), jnp.float32)
         q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
         tab = jnp.asarray(
             rng.permutation(P)[:B * npg].reshape(B, npg), jnp.int32)

@@ -17,7 +17,8 @@ Sweeps overlap on/off and writes machine-readable ``BENCH_overlap.json``:
               1F1B throughput.
   spmd        the skewed (software-pipelined) wave schedule vs the oracle
               schedule: loss/param identity, via the canonical subprocess
-              harness (tests/pipeline_equiv_main.py, mode 'overlap').
+              harness (tests/pipeline_equiv_main.py, mode 'overlap'), run
+              first, before this process initializes a JAX backend.
 
   PYTHONPATH=src python benchmarks/overlap_bench.py [--tiny] [--out PATH]
 
@@ -222,6 +223,10 @@ def main():
         archs, topos, waves = (["qwen3-0.6b", "gemma3-1b"],
                                ["single", "2node", "hetero"], 16)
         part_archs = ["qwen3-0.6b", "gemma3-1b", "granite-moe-1b-a400m"]
+    # the identity check runs in a child process; start it before this
+    # process touches a JAX backend, or on an accelerator host the parent
+    # would hold the device the child needs
+    spmd = spmd_identity(archs[0])
     doc = {
         "meta": {"mode": "tiny" if a.tiny else "full", "num_vw": NUM_VW,
                  "D": D, "pull_every": PULL_EVERY, "waves": waves,
@@ -229,7 +234,7 @@ def main():
                      "one push ~ one wave compute on hetero inter link"},
         "runtime": runtime_sweep(archs, topos, waves),
         "partitioner": partitioner_sweep(part_archs),
-        "spmd": spmd_identity(archs[0]),
+        "spmd": spmd,
     }
     with open(a.out, "w") as f:
         json.dump(doc, f, indent=2)

@@ -323,8 +323,7 @@ class Engine:
             st = self._spmd
             st["params"] = self._shard_params(st["mesh"], st["pspecs"],
                                               self._params)
-            from repro.compat import set_mesh
-            with set_mesh(st["mesh"]):
+            with jax.set_mesh(st["mesh"]):
                 st["opt_state"] = self._optimizer.init(st["params"])
         if self._serve is not None:
             st = self._serve
@@ -366,7 +365,6 @@ class Engine:
                            "cache_dt": cache_dt, "mesh": None}
             return
 
-        from repro.compat import set_mesh
         from repro.configs.base import RunConfig, ShapeConfig
         from repro.core import wave
         from repro.launch.mesh import make_mesh_auto
@@ -399,7 +397,7 @@ class Engine:
         dec_step, _, cspecs = wave.build_decode_step(rc_dec, mesh,
                                                      pos_per_row=True)
         p_sh = self._shard_params(mesh, pspecs, self._params)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             csh = jax.tree.map(
                 lambda s: NamedSharding(mesh, s), cspecs,
                 is_leaf=lambda x: isinstance(x, P))
@@ -441,7 +439,6 @@ class Engine:
                                  "decode": jax.jit(dec_fn)}
             return
 
-        from repro.compat import set_mesh
         from repro.configs.base import RunConfig, ShapeConfig
         from repro.core import wave
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -463,7 +460,7 @@ class Engine:
         dec_step, _, cspecs = wave.build_decode_step(rc_dec, mesh,
                                                      pos_per_row=True,
                                                      layout=layout)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             shardings = jax.tree.map(
                 lambda s: NamedSharding(mesh, s), cspecs,
                 is_leaf=lambda x: isinstance(x, P))
@@ -479,6 +476,18 @@ class Engine:
         self._serve_paged = {"layout": layout, "shardings": shardings,
                              "prefill": jax.jit(pre_fn),
                              "decode": jax.jit(dec_fn)}
+
+    def serve_steps(self):
+        """(prefill, decode, params): the jitted paged-executor steps the
+        Scheduler calls, with the weights they take. For ahead-of-time
+        lowering: `prefill.lower(params, prompts, lens,
+        store.prefill_input(slots)).compile()` measures compile time and
+        exposes the compiled HLO; later calls with the same shapes reuse
+        that executable."""
+        self._require_serve("serve_steps")
+        self._ensure_serve_store()
+        pg = self._serve_paged
+        return pg["prefill"], pg["decode"], self._serve["params"]
 
     def serve_store(self):
         """A fresh CacheStore (empty page pool + per-slot state) for this
@@ -770,6 +779,15 @@ class Engine:
                     params, meta = self.ps.checkpoint_state()
                     save_checkpoint(run.ckpt_dir, self._step_offset + gc,
                                     {"params": params}, meta)
+        dead = {wid: w.exception for wid, w in self.workers.items()
+                if w.exception is not None}
+        if dead:
+            # not a fault (those are typed and policy-governed below): a
+            # worker died of an error, so the run did not do its work
+            raise RuntimeError(
+                "virtual worker(s) died: " + "; ".join(
+                    f"{wid}: {e!r}" for wid, e in dead.items())
+            ) from next(iter(dead.values()))
         if run.ckpt_dir and run.ckpt_every:
             # final checkpoint: the loop wakes on push events and may exit
             # the moment the last worker dies, before the last periodic
@@ -913,7 +931,6 @@ class Engine:
     def _ensure_spmd(self):
         if self._spmd is not None:
             return
-        from repro.compat import set_mesh
         from repro.configs.base import RunConfig, ShapeConfig
         from repro.core import wave
         from repro.launch.mesh import make_mesh_auto
@@ -944,7 +961,7 @@ class Engine:
         loader = ShardedLoader(self._source, shape.global_batch, run.seq,
                                0, 1)
         p_sh = self._shard_params(mesh, pspecs, self._params)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             opt_state = self._optimizer.init(p_sh)
         self._spmd = {
             "mesh": mesh, "arch": arch, "loader": loader, "pspecs": pspecs,
@@ -956,8 +973,7 @@ class Engine:
     def _shard_params(mesh, pspecs, params):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.compat import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, P)))
@@ -965,13 +981,12 @@ class Engine:
     def _spmd_step(self) -> float:
         import jax.numpy as jnp
 
-        from repro.compat import set_mesh
         st = self._spmd
         x, y = st["loader"].next()
         # the ambient-mesh context is scoped per call rather than held open
         # for the engine's lifetime, so unrelated jax work in this process
         # never runs under a stale mesh
-        with set_mesh(st["mesh"]):
+        with jax.set_mesh(st["mesh"]):
             st["params"], st["opt_state"], m = st["jstep"](
                 st["params"], st["opt_state"],
                 {"inputs": jnp.asarray(x), "labels": jnp.asarray(y)})

@@ -265,7 +265,10 @@ class Plan:
         for name in ("stages", "tp", "data", "num_microbatches", "devices"):
             if getattr(p, name) < 0:
                 raise ValueError(f"partition.{name} must be >= 0")
-        if self.arch is not None or p.num_microbatches:
+        if self.serve is None and (self.arch is not None
+                                   or p.num_microbatches):
+            # training packs the wave batch into Nm minibatches; serve
+            # steps size their own microbatches from max_batch
             nm = self.num_microbatches
             if nm >= 1 and run.batch % nm:
                 raise ValueError(

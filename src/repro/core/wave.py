@@ -17,6 +17,7 @@ KV/SSM caches updated in the scan carry).
 from __future__ import annotations
 
 import functools
+import threading
 from dataclasses import replace as dc_replace
 from typing import Optional
 
@@ -25,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig, RunConfig
 from repro.models import lm
 from repro.models.blocks import LayerCtx, apply_layer
@@ -183,14 +183,12 @@ def pipeline_wave(cfg: ArchConfig, blocks_local, x_local, meta_local, *,
 
     buf0 = jnp.zeros((mb, S, d), x_local.dtype)
     out0 = jnp.zeros_like(x_wave)
-    # shape-(1,) carry: a rank-0 float carry becomes a scalar shard_map
-    # residual, which jax 0.4.x partial-eval mis-names ({0: axes} on rank 0)
-    aux0 = jnp.zeros((1,), jnp.float32)
+    aux0 = jnp.zeros((), jnp.float32)
     carry0 = ((buf0, jnp.zeros_like(buf0), out0, cache_local, aux0)
               if overlap else (buf0, out0, cache_local, aux0))
     final_carry, _ = jax.lax.scan(tick, carry0, jnp.arange(ticks))
     out, cache_local, aux = final_carry[-3], final_carry[-2], final_carry[-1]
-    return out.reshape(Bl, S, d), cache_local, aux[0]
+    return out.reshape(Bl, S, d), cache_local, aux
 
 
 # ----------------------------------------------------------------------------
@@ -246,7 +244,7 @@ def build_train_step(run: RunConfig, mesh: Mesh):
         # hidden broadcast GSPMD would otherwise insert for the loss.
         return _bcast_from_last(y, cfg.stages), aux / nm
 
-    pipe = shard_map(
+    pipe = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs["blocks"], P(dp, None, None), meta_specs),
         out_specs=(P(dp, None, None), P()),
@@ -350,7 +348,7 @@ def build_decode_step(run: RunConfig, mesh: Mesh, *,
             overlap=run.overlap, kernel_backend=run.kernel_backend)
         return _bcast_from_last(y, cfg.stages), cache, aux
 
-    pipe = shard_map(
+    pipe = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs["blocks"], P(dp, None, None), meta_specs, cspecs,
                   pos_spec),
@@ -416,7 +414,7 @@ def build_prefill_step(run: RunConfig, mesh: Mesh, *, cache_len: int = 0,
     in_specs = [pspecs["blocks"], P(dp, None, None), meta_specs, cspecs]
     if var_len:
         in_specs.append(P(None))
-    pipe = shard_map(
+    pipe = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(dp, None, None), cspecs, P()),
@@ -442,22 +440,45 @@ def build_prefill_step(run: RunConfig, mesh: Mesh, *, cache_len: int = 0,
 # minibatches computed with wave-start weights)
 # ----------------------------------------------------------------------------
 def build_local_wave_step(cfg: ArchConfig, nm: int, optimizer):
-    def wave_loss(params, inputs, labels):
-        def mb_loss(carry, xs):
-            x_mb, l_mb = xs
-            loss, _, _ = lm.forward_ref(cfg, params, x_mb, mode="train",
-                                        labels=l_mb)
-            return carry + loss, None
-        B = labels.shape[0]
-        xw = inputs.reshape(nm, B // nm, *inputs.shape[1:])
-        lw = labels.reshape(nm, B // nm, labels.shape[1])
-        total, _ = jax.lax.scan(mb_loss, jnp.zeros((), jnp.float32), (xw, lw))
-        return total / nm
+    """Gradients are summed microbatch by microbatch inside the scan, so
+    only one microbatch's activations are live at a time (differentiating
+    through a scan of losses would keep all Nm of them for the backward).
+
+    The threaded runtime's virtual workers share this step and the
+    process's device, so calls take turns and return the deltas on the
+    host: device memory then holds one wave's buffers at a time (two
+    concurrent qwen3-0.6b waves need more than a 16 GB TPU v5e has), and
+    the device runs one program at a time anyway."""
+    def mb_loss(params, x_mb, l_mb):
+        loss, _, _ = lm.forward_ref(cfg, params, x_mb, mode="train",
+                                    labels=l_mb)
+        return loss
+
+    mb_grad = jax.value_and_grad(mb_loss)
 
     @jax.jit
     def wave_step(params, opt_state, inputs, labels):
-        loss, grads = jax.value_and_grad(wave_loss)(params, inputs, labels)
-        deltas, opt_state = optimizer.update(grads, opt_state, params)
-        return deltas, opt_state, loss
+        def body(carry, xs):
+            total, gsum = carry
+            loss, g = mb_grad(params, *xs)
+            return (total + loss, jax.tree.map(jnp.add, gsum, g)), None
 
-    return wave_step
+        B = labels.shape[0]
+        xw = inputs.reshape(nm, B // nm, *inputs.shape[1:])
+        lw = labels.reshape(nm, B // nm, labels.shape[1])
+        carry0 = (jnp.zeros((), jnp.float32),
+                  jax.tree.map(jnp.zeros_like, params))
+        (total, gsum), _ = jax.lax.scan(body, carry0, (xw, lw))
+        grads = jax.tree.map(lambda g: g / nm, gsum)
+        deltas, opt_state = optimizer.update(grads, opt_state, params)
+        return deltas, opt_state, total / nm
+
+    lock = threading.Lock()
+
+    def locked_step(params, opt_state, inputs, labels):
+        with lock:
+            deltas, opt_state, loss = wave_step(params, opt_state, inputs,
+                                                labels)
+            return jax.device_get(deltas), opt_state, loss
+
+    return locked_step

@@ -25,7 +25,7 @@ NEG_INF = -1e30
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
-                 scale, causal, window, bq, bk, nk, gq0_last):
+                 scale, causal, window, bq, bk, nk, kv_len):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     q_start = qi * bq
@@ -38,9 +38,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     # band check: does this (q,k) tile intersect the causal/window band?
-    live = True
+    # (k tiles made only of padding past kv_len are dead too)
+    live = k_start < kv_len
     if causal:
-        live = k_start <= q_start + bq - 1
+        live = jnp.logical_and(live, k_start <= q_start + bq - 1)
     if window > 0:
         live = jnp.logical_and(live, k_start + bk - 1 > q_start - window)
 
@@ -52,7 +53,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         gq = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         gk = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = jnp.ones((bq, bk), jnp.bool_)
+        mask = gk < kv_len
         if causal:
             mask &= gq >= gk
         if window > 0:
@@ -72,26 +73,33 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         o_ref[0, 0] = (acc_sc[...] / l[:, None]).astype(o_ref.dtype)
 
 
+def _pad_to(x, n):
+    """Pad x's sequence axis (2) with zeros up to length n."""
+    pad = n - x.shape[2]
+    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+
 def flash_attention_fwd(q, k, v, *, causal=True, window=0,
                         block_q=128, block_k=128, interpret=False):
-    """q [B,H,Sq,hd]; k,v [B,KV,Sk,hd] -> [B,H,Sq,hd]."""
+    """q [B,H,Sq,hd]; k,v [B,KV,Sk,hd] -> [B,H,Sq,hd].
+
+    A sequence shorter than its block is one whole-axis block; a longer
+    one that does not divide it is padded up to a block multiple (dead
+    keys past Sk are masked, padded query rows are sliced off) rather
+    than shrinking the block to an unaligned size Mosaic refuses."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     G = H // KV
-    bq = min(block_q, Sq)
-    while Sq % bq:
-        bq -= 1
-    bk = min(block_k, Sk)
-    while Sk % bk:
-        bk -= 1
-    nq, nk = Sq // bq, Sk // bk
+    bq, bk = min(block_q, Sq), min(block_k, Sk)
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    q, k, v = _pad_to(q, nq * bq), _pad_to(k, nk * bk), _pad_to(v, nk * bk)
 
     grid = (B, H, nq, nk)
     kernel = functools.partial(
         _attn_kernel, scale=hd ** -0.5, causal=causal, window=window,
-        bq=bq, bk=bk, nk=nk, gq0_last=Sk - Sq)
+        bq=bq, bk=bk, nk=nk, kv_len=Sk)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -103,7 +111,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd),
                                lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
@@ -111,3 +119,4 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0,
         ],
         interpret=interpret,
     )(q, k, v)
+    return out[:, :, :Sq]

@@ -83,7 +83,7 @@ def decode_attention(q1, k, v, length, *, window=0, backend=None):
 
 def decode_attention_paged(q1, k_pool, v_pool, block_tab, lengths, *,
                            layer=0, backend=None):
-    """Fused paged decode: pools [groups, num_pages+1, page_size, KV, hd]
+    """Fused paged decode: pools [groups, num_pages+1, KV, page_size, hd]
     walked through block_tab [B, pages_per_slot] with per-row lengths.
     The "ref" backend gathers the paged view first (the materialization the
     kernel backends avoid)."""

@@ -67,16 +67,16 @@ def decode_paged_ref(q1, k_pool, v_pool, block_tab, lengths, *, layer=0):
     contiguous per-row view (exactly the materialization the fused kernel
     avoids), then run decode_ref with per-row lengths.
 
-    q1 [B,H,hd]; pools [groups, num_pages+1, page_size, KV, hd] (last page
-    = trash); block_tab [B, pages_per_slot] int32 (-1 = unmapped ->
+    q1 [B,H,hd]; pools [groups, num_pages+1, KV, page_size, hd] (last
+    page = trash); block_tab [B, pages_per_slot] int32 (-1 = unmapped ->
     trash); lengths scalar or [B]."""
     B = q1.shape[0]
-    groups, P1, ps, KV, hd = k_pool.shape
+    groups, P1, KV, ps, hd = k_pool.shape
     phys = jnp.where(block_tab >= 0, block_tab, P1 - 1)     # [B, npg]
 
     def view(pool):
-        pages = pool[layer][phys]                           # [B,npg,ps,KV,hd]
-        return pages.reshape(B, -1, KV, hd).transpose(0, 2, 1, 3)
+        pages = pool[layer][phys]                           # [B,npg,KV,ps,hd]
+        return pages.transpose(0, 2, 1, 3, 4).reshape(B, KV, -1, hd)
 
     return decode_ref(q1, view(k_pool), view(v_pool), lengths, window=0)
 

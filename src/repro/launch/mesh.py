@@ -10,18 +10,12 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-AUTO = getattr(jax.sharding, "AxisType", None)
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh_auto(shape, names):
-    """jax.make_mesh with Auto axis types when this jax version has them
-    (axis_types landed after 0.4.x; older versions are Auto-only anyway)."""
-    kw = {}
-    if AUTO is not None:
-        kw["axis_types"] = (AUTO.Auto,) * len(names)
-    return jax.make_mesh(shape, names, **kw)
+    """jax.make_mesh with every axis Auto (GSPMD propagates shardings)."""
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names))
 
 
 _make = make_mesh_auto
@@ -42,10 +36,8 @@ def make_logical_mesh(prod: Mesh, stages: int, tp: int) -> Mesh:
     devs = np.asarray(prod.devices)
     new_shape = devs.shape[:-1] + (stages, tp)
     new_names = tuple(names[:-1]) + ("stage", "tp")
-    kw = {}
-    if AUTO is not None:
-        kw["axis_types"] = (AUTO.Auto,) * len(new_names)
-    return Mesh(devs.reshape(new_shape), new_names, **kw)
+    return Mesh(devs.reshape(new_shape), new_names,
+                axis_types=(AxisType.Auto,) * len(new_names))
 
 
 def make_test_mesh(data=2, stages=2, tp=2) -> Mesh:

@@ -16,6 +16,8 @@ instead pushes N FIFO requests through the continuous-batching scheduler
       --batch 4 --prompt-len 24 --gen 16
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --reduced \
       --requests 8 --batch 2 --gen 8
+  python -m repro.launch.serve --arch qwen3-0.6b --full --kernel-backend tpu \
+      --requests 16 --batch 8 --prompt-len 512 --gen 32 --page-size 128
 """
 from __future__ import annotations
 
@@ -27,7 +29,13 @@ import sys
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    width = ap.add_mutually_exclusive_group()
+    width.add_argument("--reduced", dest="reduced", action="store_true",
+                       default=True,
+                       help="serve the arch's reduced() CPU config "
+                            "(default)")
+    width.add_argument("--full", dest="reduced", action="store_false",
+                       help="serve the arch at its published widths")
     ap.add_argument("--batch", type=int, default=4,
                     help="decode batch slots (ServeSpec.max_batch)")
     ap.add_argument("--prompt-len", type=int, default=24)
@@ -117,6 +125,8 @@ def main(argv=None):
     from repro.api import Engine, PartitionSpec, Plan, RunSpec, ServeSpec
     from repro.api.serving import Request, Scheduler
     from repro.configs import ARCHS, reduced as make_reduced
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = ARCHS[a.arch]
     if a.reduced:

@@ -97,6 +97,8 @@ def main(argv=None):
 
     from repro.api import ClusterSpec, Engine, PartitionSpec, Plan, \
         RunSpec, WSP
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import ARCHS, reduced as make_reduced
     from repro.obs import NULL_TRACER, Tracer
 

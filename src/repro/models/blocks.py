@@ -208,7 +208,7 @@ def _attn_decode(cfg: ArchConfig, p, xn, ctx: LayerCtx, cache):
         kf, vf = cache["kv_full"]
         i = jnp.asarray(ctx.full_i)
         tab = cache["block_tab"]
-        cap = tab.shape[1] * kf.shape[2]                # pps * page_size
+        cap = tab.shape[1] * kf.shape[3]                # pps * page_size
         pos_b = jnp.broadcast_to(pos_a, (B,))
         sel = jnp.asarray(ctx.kind == 0) & jnp.asarray(ctx.valid)
         sel_b = jnp.broadcast_to(sel, (B,)) & (pos_b >= 0) & (pos_b < cap)

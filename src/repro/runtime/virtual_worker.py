@@ -126,6 +126,7 @@ class VirtualWorker(threading.Thread):
         self.crashed = False            # died without deregistering
         self.evicted = False            # supervisor pulled us from the clock
         self.error = None               # the FaultError that took us down
+        self.exception = None           # any other exception that did
         self.params = None
         self._outbox: Optional[_Outbox] = None
         self._inflight: Optional[_PushHandle] = None
@@ -273,7 +274,13 @@ class VirtualWorker(threading.Thread):
                     tr.metrics.counter_inc("fault/crashes")
                 self.ps.deregister(self.wid)
                 return
-            raise
+            # anything else (a bug, a device out of memory, a compiler
+            # refusal) is no fault the fleet recovers from: record it for
+            # fit() to raise, stop the fleet, and leave the clock so no
+            # peer waits at the gate for a worker that is gone
+            self.exception = e
+            self.stop_event.set()
+            self.ps.deregister(self.wid)
         finally:
             if self._outbox is not None:
                 self._outbox.close()

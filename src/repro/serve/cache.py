@@ -13,9 +13,11 @@ Two layouts, one API:
                degenerates to it (one page per slot). `cache_struct` /
                `init_cache` build it; `lm.cache_struct` delegates here.
 
-  paged        full-attention K/V live in a fixed pool of pages
-               `[groups, num_pages + 1, page_size, KV, hd]` (the extra
-               page is a write-off target for unmapped slots) indexed
+  paged        full-attention K/V live in a fixed pool of head-major
+               pages `[groups, num_pages + 1, KV, page_size, hd]` (the
+               extra page is a write-off target for unmapped slots; each
+               page's per-head [page_size, hd] tile is a whole block for
+               the paged decode kernel at any page size) indexed
                through a per-slot block table `block_tab [max_batch,
                pages_per_slot]` (−1 = unmapped). Reads gather a per-row
                page view; writes scatter page-granularly. Fixed-size
@@ -194,11 +196,11 @@ def paged_struct(cfg, layout: PageLayout, *, dtype=jnp.bfloat16):
         st = cfg.stages
         kv_tp = T_AX if (cfg.num_kv_heads and cfg.tp > 1
                          and cfg.num_kv_heads % cfg.tp == 0) else None
-        shp = (st * meta["m_full"], layout.num_pages + 1, layout.page_size,
-               cfg.num_kv_heads, cfg.head_dim)
+        shp = (st * meta["m_full"], layout.num_pages + 1, cfg.num_kv_heads,
+               layout.page_size, cfg.head_dim)
         shapes["kv_full"] = tuple(jax.ShapeDtypeStruct(shp, dtype)
                                   for _ in range(2))
-        specs["kv_full"] = tuple(P(S_AX, None, None, kv_tp, None)
+        specs["kv_full"] = tuple(P(S_AX, None, kv_tp, None, None)
                                  for _ in range(2))
     shapes["block_tab"] = jax.ShapeDtypeStruct(
         (layout.max_batch, layout.pages_per_slot), jnp.int32)
@@ -228,14 +230,14 @@ def _phys(tab, trash):
 def page_view(pool, i, tab):
     """Gather one group's per-row contiguous view through the block table.
 
-    pool [m, NP+1, ps, KV, hd]; tab [B, pps]. Returns (view [B, pps*ps,
+    pool [m, NP+1, KV, ps, hd]; tab [B, pps]. Returns (view [B, pps*ps,
     KV, hd], gpos [B, pps*ps]) where gpos is the global position of each
     gathered slot, -1 for unmapped pages (decode_attend masks those)."""
     B, pps = tab.shape
-    ps = pool.shape[2]
-    grp = pool[i]                                       # [NP+1, ps, KV, hd]
-    view = grp[_phys(tab, pool.shape[1] - 1)]           # [B, pps, ps, KV, hd]
-    view = view.reshape(B, pps * ps, *pool.shape[3:])
+    KV, ps, hd = pool.shape[2:]
+    grp = pool[i]                                       # [NP+1, KV, ps, hd]
+    view = grp[_phys(tab, pool.shape[1] - 1)]           # [B, pps, KV, ps, hd]
+    view = view.transpose(0, 1, 3, 2, 4).reshape(B, pps * ps, KV, hd)
     gpos = jnp.arange(pps * ps, dtype=jnp.int32)[None, :]
     gpos = jnp.where(jnp.repeat(tab >= 0, ps, axis=1), gpos, -1)
     return view, gpos
@@ -243,17 +245,17 @@ def page_view(pool, i, tab):
 
 def page_write_token(pool, i, tab, pos, new_row, sel):
     """Decode-time single-token scatter: row b's token lands in the page
-    holding logical position pos[b]. pool [m, NP+1, ps, KV, hd]; tab
+    holding logical position pos[b]. pool [m, NP+1, KV, ps, hd]; tab
     [B, pps]; pos, sel [B]; new_row [B, 1, KV, hd]. Rows with sel False or
     an unmapped page write to the trash page instead (never read)."""
     B, pps = tab.shape
-    ps = pool.shape[2]
+    ps = pool.shape[3]
     trash = pool.shape[1] - 1
     lp = jnp.clip(pos // ps, 0, pps - 1)
     off = jnp.clip(pos, 0, None) % ps
     phys = tab[jnp.arange(B), lp]                       # [B]
     phys = jnp.where(sel & (phys >= 0), phys, trash)
-    return pool.at[i, phys, off].set(new_row[:, 0].astype(pool.dtype))
+    return pool.at[i, phys, :, off].set(new_row[:, 0].astype(pool.dtype))
 
 
 def page_write_prompt(pool, i, tab, new_kv, sel, lens=None):
@@ -263,20 +265,20 @@ def page_write_prompt(pool, i, tab, new_kv, sel, lens=None):
     length prompts write only their real tokens). Rows with sel False or
     unmapped pages scatter into the trash page."""
     B, S = new_kv.shape[:2]
-    ps = pool.shape[2]
+    ps = pool.shape[3]
     trash = pool.shape[1] - 1
     pp_in = math.ceil(S / ps)
     pad = pp_in * ps - S
     kv = jnp.pad(new_kv, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else new_kv
-    kv = kv.reshape(B, pp_in, ps, *new_kv.shape[2:])
+    kv = kv.reshape(B, pp_in, ps, *new_kv.shape[2:]).transpose(0, 1, 3, 2, 4)
     tabp = tab[:, :pp_in]
     sel_b = jnp.broadcast_to(jnp.asarray(sel), (B,))
     phys = jnp.where(sel_b[:, None] & (tabp >= 0), tabp, trash)  # [B, pp_in]
     gpos = jnp.arange(pp_in * ps).reshape(pp_in, ps)             # [pp_in, ps]
     live = (gpos[None] < S) if lens is None else \
         (gpos[None] < jnp.minimum(lens, S)[:, None, None])       # [B,pp,ps]
-    old = pool[i][phys]                                 # [B, pp_in, ps, KV, hd]
-    upd = jnp.where(live[..., None, None], kv.astype(pool.dtype), old)
+    old = pool[i][phys]                                 # [B, pp_in, KV, ps, hd]
+    upd = jnp.where(live[:, :, None, :, None], kv.astype(pool.dtype), old)
     return pool.at[i, phys].set(upd)
 
 
@@ -593,8 +595,8 @@ class CacheStore:
         if "kv_full" in self.tree:
             k, _ = self.tree["kv_full"]
             # one page across both K and V pools, all layer groups
-            page_bytes = 2 * k.shape[0] * lo.page_size * int(
-                np.prod(k.shape[3:])) * k.dtype.itemsize
+            page_bytes = 2 * k.shape[0] * int(
+                np.prod(k.shape[2:])) * k.dtype.itemsize
         slot_bytes = 0
         for key in SLOT_KEYS:
             if key in self.tree:

@@ -13,7 +13,6 @@ import jax.numpy as jnp                      # noqa: E402
 import numpy as np                           # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh                # noqa: E402
 from repro.configs import ARCHS, reduced, RunConfig, ShapeConfig  # noqa: E402
 from repro.core import wave                  # noqa: E402
 from repro.models import lm                  # noqa: E402
@@ -48,7 +47,7 @@ def main(arch_name: str, mode: str = "train") -> int:
                             compute_dtype="float32", loss_chunk=16,
                             overlap=overlap)
             step, _ = wave.build_train_step(run, mesh)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 p_sh = jax.device_put(params, jax.tree.map(
                     lambda s: NamedSharding(mesh, s), pspecs,
                     is_leaf=lambda x: isinstance(x, P)))
@@ -80,7 +79,7 @@ def main(arch_name: str, mode: str = "train") -> int:
                                     dtype=jnp.int32)
         step, _ = wave.build_train_step(run, mesh)
         opt = make_optimizer("sgd", 0.1)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p_sh = jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, P)))
@@ -118,7 +117,7 @@ def main(arch_name: str, mode: str = "train") -> int:
         run_o = RunConfig(arch=cfg, shape=shape, compute_dtype="float32",
                           overlap=overlap)
         step, pspecs2, cspecs = wave.build_decode_step(run_o, mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p_sh = jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, P)))

@@ -38,7 +38,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.api import (Engine, PartitionSpec, Plan, RunSpec,  # noqa: E402
                        ServeSpec)
 from repro.api.serving import Request, Scheduler  # noqa: E402
-from repro.compat import set_mesh                 # noqa: E402
 from repro.configs import ARCHS, reduced, RunConfig, ShapeConfig  # noqa: E402
 from repro.core import wave                  # noqa: E402
 from repro.launch.mesh import make_mesh_auto  # noqa: E402
@@ -66,7 +65,7 @@ def step_level_parity(cfg, params, pspecs, prompts) -> None:
                        **common)
     pre_step, _, _ = wave.build_prefill_step(rc_pre, mesh, cache_len=max_len)
     dec_step, _, _ = wave.build_decode_step(rc_dec, mesh, pos_per_row=True)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_sh = jax.device_put(params, jax.tree.map(
             lambda s: NamedSharding(mesh, s), pspecs,
             is_leaf=lambda x: isinstance(x, P)))
@@ -87,7 +86,7 @@ def step_level_parity(cfg, params, pspecs, prompts) -> None:
     dd = 0.0
     for t in range(1, GEN):
         pos = jnp.full((B,), PROMPT + t - 1, jnp.int32)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             logits, cache = jax.jit(dec_step)(
                 p_sh, {"inputs": tok[:, None], "cache": cache, "pos": pos})
         hid, ref_cache, _ = lm.forward_ref(

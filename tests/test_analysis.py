@@ -58,11 +58,10 @@ def test_psum_ring_factor():
     mesh = make_mesh_auto((1,), ("x",))
 
     def f(x):
-        from repro.compat import shard_map
-        return shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
+        return jax.shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
                              in_specs=jax.sharding.PartitionSpec(None),
                              out_specs=jax.sharding.PartitionSpec(None),
-                         check_vma=False)(x)
+                             check_vma=False)(x)
     jaxpr = jax.make_jaxpr(f)(jnp.ones((128,), jnp.float32))
     c = analyze_jaxpr(jaxpr.jaxpr, {"x": 4}, total_devices=4)
     # ring all-reduce: 2*(n-1)/n * payload = 1.5 * 512B

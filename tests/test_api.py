@@ -189,6 +189,34 @@ def test_threads_fit_is_single_shot():
         eng.fit()                 # would return an empty report otherwise
 
 
+@pytest.mark.parametrize("allow_degraded", [False, True])
+def test_worker_error_fails_fit(allow_degraded):
+    """A virtual worker that dies of an ordinary exception (a device out
+    of memory, a compiler refusal) fails fit() even where degraded runs
+    are allowed: no FaultPolicy recovers from it. Its peer is not left
+    waiting at the staleness gate."""
+    from repro.api import FaultPolicy
+    calls = []
+
+    def flaky_step(params, opt_state, x, y):
+        calls.append(1)
+        if len(calls) == 3:
+            raise MemoryError("RESOURCE_EXHAUSTED: out of device memory")
+        return {"w": np.ones(4, np.float32)}, opt_state, 1.0
+
+    opt = types.SimpleNamespace(init=lambda p: None)
+    plan = Plan(cluster=ClusterSpec(num_vw=2), sync=WSP(D=1),
+                run=RunSpec(max_waves=6, batch=2, seq=8, vocab=16),
+                fault_policy=FaultPolicy(allow_degraded=allow_degraded,
+                                         gate_timeout_s=30.0))
+    eng = Engine(plan, params={"w": np.zeros(4, np.float32)},
+                 wave_step=flaky_step, optimizer=opt)
+    with pytest.raises(RuntimeError, match="died.*RESOURCE_EXHAUSTED") as ei:
+        eng.fit()
+    assert isinstance(ei.value.__cause__, MemoryError)
+    assert sum(w.exception is not None for w in eng.workers.values()) == 1
+
+
 def test_bsp_checkpoints_and_resumes():
     """The BSP loop honors ckpt_dir/ckpt_every/resume like the other
     backends (checkpoint at the cadence AND at end of run, numbering
@@ -487,6 +515,29 @@ def test_launch_train_spmd_routes_through_engine(capsys):
                 "--batch", "4", "--seq", "32"])
     out = capsys.readouterr().out
     assert "mesh=(1,1,1)" in out and "wave " in out
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The entry points' persistent compile cache follows
+    JAX_COMPILATION_CACHE_DIR when it is set (setting nothing itself), and
+    a fixed <checkout>/.jax_cache otherwise."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    calls = []
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert calls == [("jax_compilation_cache_dir", want)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert compile_cache.enable_compile_cache() == env_dir
+        assert calls == []
+    assert jax.config.jax_compilation_cache_dir == before
 
 
 def test_launch_topology_list(capsys):

@@ -22,6 +22,7 @@ def _tol(dtype):
 
 @pytest.mark.parametrize("B,H,KV,S,hd", [
     (1, 2, 2, 64, 16), (2, 4, 2, 128, 32), (1, 8, 1, 96, 64),
+    (1, 4, 2, 72, 16),            # S not a block multiple: padded + masked
 ])
 @pytest.mark.parametrize("window", [0, 40])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -115,15 +116,15 @@ def test_flash_decode_unaligned_cache_pads_not_degrades(caplog):
 
 @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.float8_e4m3fn])
 def test_flash_decode_paged(pool_dtype):
-    """The paged kernel walks the stacked pool [groups, pages+1, ps, KV,
+    """The paged kernel walks the stacked pool [groups, pages+1, KV, ps,
     hd] through the block table inside the index map: unmapped (-1)
     entries route to the trash page, rows mask at their own length, and
     a length-0 row emits zeros."""
     L, P1, ps, B, KV, G, hd = 2, 9, 4, 3, 2, 2, 16
     H = KV * G
     ks = jax.random.split(KEY, 4)
-    pool_k = jax.random.normal(ks[0], (L, P1, ps, KV, hd)).astype(pool_dtype)
-    pool_v = jax.random.normal(ks[1], (L, P1, ps, KV, hd)).astype(pool_dtype)
+    pool_k = jax.random.normal(ks[0], (L, P1, KV, ps, hd)).astype(pool_dtype)
+    pool_v = jax.random.normal(ks[1], (L, P1, KV, ps, hd)).astype(pool_dtype)
     q1 = jax.random.normal(ks[2], (B, H, hd))
     tab = jnp.asarray([[0, 3, 6], [1, 4, -1], [-1, -1, -1]], jnp.int32)
     lens = jnp.asarray([11, 6, 0], jnp.int32)
@@ -150,12 +151,12 @@ def test_flash_decode_paged_matches_contiguous():
     P1 = B * npg + 1
     perm = np.random.default_rng(3).permutation(B * npg)
     tab = jnp.asarray(perm.reshape(B, npg), jnp.int32)
-    pool_k = jnp.zeros((1, P1, ps, KV, hd))
-    pool_v = jnp.zeros((1, P1, ps, KV, hd))
+    pool_k = jnp.zeros((1, P1, KV, ps, hd))
+    pool_v = jnp.zeros((1, P1, KV, ps, hd))
     for b in range(B):
         for pi in range(npg):
-            blk_k = k[b, :, pi * ps:(pi + 1) * ps].transpose(1, 0, 2)
-            blk_v = v[b, :, pi * ps:(pi + 1) * ps].transpose(1, 0, 2)
+            blk_k = k[b, :, pi * ps:(pi + 1) * ps]
+            blk_v = v[b, :, pi * ps:(pi + 1) * ps]
             pool_k = pool_k.at[0, perm[b * npg + pi]].set(blk_k)
             pool_v = pool_v.at[0, perm[b * npg + pi]].set(blk_v)
     lens = jnp.asarray([S, S - 3], jnp.int32)
@@ -201,7 +202,8 @@ def test_ops_dispatch_uses_ambient_backend():
                                rtol=2e-5)
 
 
-@pytest.mark.parametrize("B,H,S,hd", [(1, 2, 64, 16), (2, 3, 96, 32)])
+@pytest.mark.parametrize("B,H,S,hd", [(1, 2, 64, 16), (2, 3, 96, 32),
+                                       (1, 2, 50, 16)])   # 50: padded
 @pytest.mark.parametrize("chunk", [8, 16])
 def test_rwkv6_chunked(B, H, S, hd, chunk):
     ks = jax.random.split(KEY, 5)
@@ -218,7 +220,8 @@ def test_rwkv6_chunked(B, H, S, hd, chunk):
                                rtol=5e-4)
 
 
-@pytest.mark.parametrize("B,H,S,N,P", [(1, 2, 64, 8, 16), (2, 4, 128, 16, 32)])
+@pytest.mark.parametrize("B,H,S,N,P", [(1, 2, 64, 8, 16), (2, 4, 128, 16, 32),
+                                       (1, 2, 100, 8, 16)])  # 100: padded
 @pytest.mark.parametrize("chunk", [16, 64])
 def test_ssd_chunked(B, H, S, N, P, chunk):
     ks = jax.random.split(KEY, 4)

@@ -33,6 +33,39 @@ def _final_loss(report, last=8):
     return float(np.mean(ys[-last:]))
 
 
+def test_local_wave_step_takes_turns_and_returns_host_deltas(monkeypatch):
+    """Virtual workers share one device: their wave steps never run at
+    once, and the deltas come back on the host, so the device holds one
+    wave's buffers at a time."""
+    import threading
+    import time
+    params, opt, step = _setup()
+    x = np.zeros((4, 16), np.int32)
+    step(params, opt.init(params), x, x)            # compile outside the race
+    running, seen = [], []
+    device_get = jax.device_get
+
+    def slow_device_get(tree):      # widens the window a second call could use
+        running.append(1)
+        seen.append(len(running))
+        time.sleep(0.05)
+        running.pop()
+        return device_get(tree)
+
+    monkeypatch.setattr(jax, "device_get", slow_device_get)
+    results = []
+    ts = [threading.Thread(target=lambda: results.append(
+        step(params, opt.init(params), x, x))) for _ in range(3)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    assert len(results) == 3 and max(seen) == 1
+    for deltas, _, loss in results:
+        assert all(isinstance(l, np.ndarray) for l in jax.tree.leaves(deltas))
+        assert np.isfinite(float(loss))
+
+
 def test_wsp_trainer_converges():
     params, opt, step = _setup()
     tr = WSPTrainer(params, step, opt, num_vw=2, D=1, batch=8, seq=32,

@@ -87,6 +87,25 @@ def test_serve_plan_validation():
              partition=PartitionSpec(stages=2, tp=1, data=1, devices=2))
 
 
+def test_unreduced_serve_plan_builds_without_materializing():
+    """Published widths get through the serve entry points: the training-
+    only microbatch packing check does not apply to a serve Plan (qwen3's
+    16 microbatches vs 8 decode slots), building the Engine allocates
+    nothing, and the CLI has a switch for the full config."""
+    from repro.launch.serve import build_parser
+    arch = ARCHS["qwen3-0.6b"]
+    plan = Plan(arch=arch, serve=ServeSpec(
+        prompt_len=512, gen=32, max_batch=8, page_size=128,
+        kernel_backend="tpu"))
+    eng = Engine(plan)
+    assert eng._params is None and eng._serve is None
+    assert plan.arch.d_model == 1024 and plan.arch.num_layers == 28
+    with pytest.raises(ValueError, match="not divisible by num_microbatches"):
+        Plan(arch=arch, run=RunSpec(batch=8))       # training still checks
+    assert build_parser().parse_args(["--full"]).reduced is False
+    assert build_parser().parse_args([]).reduced is True
+
+
 def test_engine_surface_refuses_mismatched_plans():
     cfg = _cfg("qwen3-0.6b")
     serve_plan = Plan(arch=cfg, serve=ServeSpec(prompt_len=8, gen=4,

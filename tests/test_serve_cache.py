@@ -325,7 +325,7 @@ def test_flash_decode_gathered_pages_matches_contiguous():
     # pool with a deliberately permuted page assignment
     pages = rng.permutation(lo.num_pages).reshape(B, lo.pages_per_slot)
     tab = jnp.asarray(pages, jnp.int32)
-    pool_shape = (1, lo.num_pages + 1, ps, KV, hd)
+    pool_shape = (1, lo.num_pages + 1, KV, ps, hd)
     pool_k = jnp.zeros(pool_shape, jnp.float32)
     pool_v = jnp.zeros(pool_shape, jnp.float32)
     sel = jnp.ones((B,), bool)
@@ -374,7 +374,7 @@ def test_flash_decode_paged_pool_direct_matches_gather_view():
     # rows only own the pages their depth needs; the rest are unmapped
     tab = tab.at[1, 2:].set(-1)
     tab = tab.at[2, :].set(-1)
-    pool_shape = (1, lo.num_pages + 1, ps, KV, hd)
+    pool_shape = (1, lo.num_pages + 1, KV, ps, hd)
     pool_k = cache_lib.page_write_prompt(jnp.zeros(pool_shape), 0, tab, k,
                                          jnp.ones((B,), bool))
     pool_v = cache_lib.page_write_prompt(jnp.zeros(pool_shape), 0, tab, v,
@@ -437,17 +437,52 @@ def test_scheduler_kernel_backend_fp8_stream_parity():
     assert run("ref") == run("interpret")
 
 
+@pytest.mark.parametrize("kb", ["ref", "interpret"])
+def test_paged_decode_logits_match_contiguous(kb):
+    """Prefill + decode through the paged pool reproduces the contiguous
+    cache's logits at positions past the first pages: each decode token
+    lands in its own page (a capacity miscounted from the pool's axes
+    would send it to the trash page instead)."""
+    cfg = _cfg("qwen3-0.6b")
+    B, P, gen, ps = 3, 20, 3, 8
+    params, _ = lm.init_params(cfg, jax.random.PRNGKey(4))
+    prompts = jnp.asarray(np.random.default_rng(4).integers(
+        0, cfg.vocab_size, (B, P)), jnp.int32)
+
+    def engine(page_size):
+        return Engine(Plan(arch=cfg, serve=ServeSpec(
+            prompt_len=P, gen=gen, max_batch=B, page_size=page_size,
+            kernel_backend=kb)), params=params)
+
+    contig = engine(0)
+    want_pf, cache = contig.prefill(prompts)
+    paged = engine(ps)
+    store = paged.serve_store()
+    for s in range(B):
+        store.alloc(s, P + gen)
+    got_pf = paged.prefill_into(store, prompts, np.full(B, P), list(range(B)))
+    np.testing.assert_allclose(np.asarray(got_pf), np.asarray(want_pf),
+                               rtol=1e-5, atol=1e-5)
+    tok = jnp.argmax(want_pf, axis=-1).astype(jnp.int32)[:, None]
+    for t in range(gen - 1):
+        want, cache = contig.decode(tok, cache, P + t)
+        got, _ = paged.decode(tok, store, np.full(B, P + t))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        tok = jnp.argmax(want, axis=-1).astype(jnp.int32)[:, None]
+
+
 def test_page_write_token_routes_unmapped_to_trash():
     """Decode writes for unmapped rows land in the trash page, never in a
     live page; mapped rows land at (page, offset) of their position."""
     lo = make_layout(2, 8, page_size=4)
-    pool = jnp.zeros((1, lo.num_pages + 1, 4, 1, 2), jnp.float32)
+    pool = jnp.zeros((1, lo.num_pages + 1, 1, 4, 2), jnp.float32)
     tab = jnp.asarray([[0, 2], [-1, -1]], jnp.int32)
     row = jnp.ones((2, 1, 1, 2), jnp.float32)
     pos = jnp.asarray([5, 6], jnp.int32)
     out = cache_lib.page_write_token(pool, 0, tab, pos,
                                      row, jnp.asarray([True, True]))
     out = np.asarray(out)
-    assert np.all(out[0, 2, 1] == 1.0)          # row 0: page 2, offset 1
+    assert np.all(out[0, 2, :, 1] == 1.0)       # row 0: page 2, offset 1
     assert np.all(out[0, :lo.num_pages].sum() == 2.0)  # nothing else live
-    assert np.all(out[0, lo.trash_page, 2] == 1.0)     # row 1 -> trash
+    assert np.all(out[0, lo.trash_page, :, 2] == 1.0)  # row 1 -> trash
