@@ -598,12 +598,16 @@ class Engine:
         if pos.ndim == 0:
             # one trace serves both aligned and per-row decode
             pos = jnp.broadcast_to(pos, (sv.max_batch,))
+        tokens = jnp.asarray(tokens)
+        # engine/decode: the call to its logits on the device;
+        # engine/decode_dispatch: the jitted call alone, until it returns
         if isinstance(cache, CacheStore):
             self._ensure_serve_store()
             st, pg = self._serve, self._serve_paged
             with self.tracer.span("engine", "decode"):
-                logits, out = pg["decode"](st["params"], jnp.asarray(tokens),
-                                           cache.tree, pos)
+                with self.tracer.span("engine", "decode_dispatch"):
+                    logits, out = pg["decode"](st["params"], tokens,
+                                               cache.tree, pos)
                 if self.tracer.enabled:
                     jax.block_until_ready(logits)
             cache.update(out)
@@ -611,8 +615,8 @@ class Engine:
         self._ensure_serve()
         st = self._serve
         with self.tracer.span("engine", "decode"):
-            logits, cache = st["decode"](st["params"], jnp.asarray(tokens),
-                                         cache, pos)
+            with self.tracer.span("engine", "decode_dispatch"):
+                logits, cache = st["decode"](st["params"], tokens, cache, pos)
             if self.tracer.enabled:
                 jax.block_until_ready(logits)
         return logits[:, -1], cache

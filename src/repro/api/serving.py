@@ -252,8 +252,6 @@ class Scheduler:
                 free.sort()
             preempted_rids.add(slot.req.rid)
             report.preemptions += 1
-            tr.instant("sched", "preempt", rid=slot.req.rid, slot=s,
-                       step=step, tokens=len(slot.stats.tokens))
             tr.metrics.counter_inc("serve/preemptions")
             return (slot.prompt, slot.req)
 
@@ -342,146 +340,149 @@ class Scheduler:
 
         faulted_steps: set[int] = set()
         while queue or active:
-            # ---- graceful degradation under sustained fault pressure ----
-            if queue and fpol.shed_after_faults \
-                    and report.slot_faults >= fpol.shed_after_faults:
-                shed_queue("fault_pressure")
-            if queue and not active and not free:
-                # every slot is quarantined: nothing can ever be admitted
-                # again — shed the remainder instead of spinning forever
-                shed_queue("no_healthy_slots")
-            # ---- admit: policy order into the lowest slots, page-gated --
-            if free and queue:
-                admits = []
-                order = self._admit_order([r for _, r in queue], step)
-                taken = []
-                requeued = []
-                for qi in order:
-                    if not free:
-                        break
-                    prompt, r = queue[qi]
-                    need = prompt.shape[0] + self._limit(r)
-                    hit, pages, need_fresh = mm.plan_admit(prompt, need)
-                    if not mm.make_room(need_fresh, protect=pages):
-                        # pool exhausted: before refusing, try to preempt
-                        # an in-flight victim (never one that was already
-                        # preempted — bounds preemptions at one per rid)
-                        vict = (mm.victim(active, step, need_fresh)
-                                if r.rid not in preempted_rids else None)
-                        if vict is not None:
-                            requeued.append(preempt_slot(vict))
-                        if vict is None \
-                                or not mm.make_room(need_fresh,
-                                                    protect=pages):
-                            # stop admitting rather than over-reserving;
-                            # retirements will free pages
-                            report.admit_blocked += 1
-                            tr.instant("sched", "refuse", rid=r.rid,
-                                       step=step, need_tokens=need,
-                                       pages_in_use=store.pages_in_use)
+            # one pass: admit, prefill, fault injection, decode, retire;
+            # its self time (minus prefill_group and decode_step) is the
+            # Scheduler's own host work, during which nothing is dispatched
+            with tr.span("sched", "step", step=step):
+                # ---- graceful degradation under sustained fault pressure ----
+                if queue and fpol.shed_after_faults \
+                        and report.slot_faults >= fpol.shed_after_faults:
+                    shed_queue("fault_pressure")
+                if queue and not active and not free:
+                    # every slot is quarantined: nothing can ever be admitted
+                    # again — shed the remainder instead of spinning forever
+                    shed_queue("no_healthy_slots")
+                # ---- admit: policy order into the lowest slots, page-gated --
+                if free and queue:
+                    admits = []
+                    order = self._admit_order([r for _, r in queue], step)
+                    taken = []
+                    requeued = []
+                    for qi in order:
+                        if not free:
                             break
-                    s = free.pop(0)
-                    skip = mm.admit(s, prompt, need, hit, pages, step)
-                    taken.append(qi)
-                    admits.append((r, prompt, s, skip))
-                for qi in sorted(taken, reverse=True):
-                    del queue[qi]
-                # preempted victims re-enter at the queue front (inserted
-                # only after the del loop — `taken` indexes the old queue)
-                for item in reversed(requeued):
-                    queue.insert(0, item)
-                if admits:
-                    group = report.prefill_calls
-                    for r, prompt, s, skip in admits:
-                        tr.instant("sched", "admit", rid=r.rid, slot=s,
-                                   step=step, group=group,
-                                   prompt_len=prompt.shape[0],
-                                   shared_pages=skip,
-                                   pages_in_use=store.pages_in_use)
-                    prompts = np.zeros((B, P), np.int32)
-                    lens = np.ones(B, np.int32)
-                    for j, (r, prompt, _, _) in enumerate(admits):
-                        prompts[j, :prompt.shape[0]] = prompt
-                        lens[j] = prompt.shape[0]
-                    t0 = time.monotonic()
-                    with tr.span("sched", "prefill_group", group=group,
-                                 rows=len(admits)):
-                        logits = np.asarray(eng.prefill_into(
-                            store, prompts, lens,
-                            [s for _, _, s, _ in admits],
-                            skip_pages=[skip for *_, skip in admits]))
-                    dt = time.monotonic() - t0
-                    report.prefill_s += dt
-                    report.prefill_calls += 1
-                    # TTFT: arrival (run start — all requests arrive
-                    # together) to the end of this admission group's
-                    # prefill; the group's cost enters each member once
-                    ttft = time.monotonic() - t_start
-                    for j, (r, prompt, s, _) in enumerate(admits):
-                        tok = self._pick_one(logits[j], r.rid, 0, key)
-                        stats = RequestStats(rid=r.rid,
-                                             prompt_len=prompt.shape[0],
-                                             tokens=[tok],
-                                             admitted_step=step,
-                                             slot=s, group=group,
-                                             prefill_s=dt, ttft_s=ttft,
-                                             retries=retries_by_rid.get(
-                                                 r.rid, 0))
-                        tr.metrics.observe("serve/ttft_s", ttft)
-                        slot = _Slot(r, stats, self._limit(r),
-                                     next_pos=prompt.shape[0], last_tok=tok,
-                                     t_admit=t0, prompt=prompt)
-                        if len(stats.tokens) >= slot.limit:
-                            retire(s, slot)
-                        else:
-                            active[s] = slot
-            # ---- injected slot faults fire at their decode step ---------
-            if injector is not None and step not in faulted_steps:
-                # consulted once per step value: a recovery that empties
-                # the batch loops back here without re-firing the fault
-                faulted_steps.add(step)
-                inject_slot_faults()
-            if not active:
-                continue
-            # ---- one batched decode step over every active slot ---------
-            toks = np.zeros((B, 1), np.int32)
-            pos = np.zeros(B, np.int32)
-            for s, slot in active.items():
-                toks[s, 0] = slot.last_tok
-                pos[s] = slot.next_pos
-            t0 = time.monotonic()
-            with tr.span("sched", "decode_step", step=step,
-                         slots=len(active), pages=store.pages_in_use):
-                logits, _ = eng.decode(toks, store, pos)
-                logits = np.asarray(logits)
-            report.decode_s += time.monotonic() - t0
-            report.decode_steps += 1
-            report.slot_steps += len(active)
-            report.page_steps += store.pages_in_use
-            tr.counter("sched", "active_slots", len(active))
-            tr.counter("sched", "pages_in_use", store.pages_in_use)
-            step += 1
-            # ---- advance / retire --------------------------------------
-            for s in sorted(active):
-                slot = active[s]
-                tok = self._pick_one(logits[s], slot.req.rid,
-                                     len(slot.stats.tokens), key)
-                slot.stats.tokens.append(tok)
-                slot.next_pos += 1
-                slot.last_tok = tok
-                if len(slot.stats.tokens) >= slot.limit:
-                    del active[s]
-                    retire(s, slot)
-            if callback is not None:
-                try:
-                    callback(step, len(active))
-                except StopServing:
-                    # abort: the replica died — report only what retired;
-                    # the Router replays the rest on the survivors
-                    report.aborted_step = step
-                    tr.instant("sched", "aborted", step=step,
-                               in_flight=len(active), queued=len(queue))
-                    break
+                        prompt, r = queue[qi]
+                        need = prompt.shape[0] + self._limit(r)
+                        hit, pages, need_fresh = mm.plan_admit(prompt, need)
+                        if not mm.make_room(need_fresh, protect=pages):
+                            # pool exhausted: before refusing, try to preempt
+                            # an in-flight victim (never one that was already
+                            # preempted — bounds preemptions at one per rid)
+                            vict = (mm.victim(active, step, need_fresh)
+                                    if r.rid not in preempted_rids else None)
+                            if vict is not None:
+                                requeued.append(preempt_slot(vict))
+                            if vict is None \
+                                    or not mm.make_room(need_fresh,
+                                                        protect=pages):
+                                # stop admitting rather than over-reserving;
+                                # retirements will free pages
+                                report.admit_blocked += 1
+                                tr.instant("sched", "refuse", rid=r.rid,
+                                           step=step, need_tokens=need,
+                                           pages_in_use=store.pages_in_use)
+                                break
+                        s = free.pop(0)
+                        skip = mm.admit(s, prompt, need, hit, pages, step)
+                        taken.append(qi)
+                        admits.append((r, prompt, s, skip))
+                    for qi in sorted(taken, reverse=True):
+                        del queue[qi]
+                    # preempted victims re-enter at the queue front (inserted
+                    # only after the del loop — `taken` indexes the old queue)
+                    for item in reversed(requeued):
+                        queue.insert(0, item)
+                    if admits:
+                        group = report.prefill_calls
+                        for r, prompt, s, skip in admits:
+                            tr.instant("sched", "admit", rid=r.rid, slot=s,
+                                       step=step, group=group,
+                                       prompt_len=prompt.shape[0],
+                                       shared_pages=skip,
+                                       pages_in_use=store.pages_in_use)
+                        prompts = np.zeros((B, P), np.int32)
+                        lens = np.ones(B, np.int32)
+                        for j, (r, prompt, _, _) in enumerate(admits):
+                            prompts[j, :prompt.shape[0]] = prompt
+                            lens[j] = prompt.shape[0]
+                        t0 = time.monotonic()
+                        with tr.span("sched", "prefill_group", group=group,
+                                     rows=len(admits)):
+                            logits = np.asarray(eng.prefill_into(
+                                store, prompts, lens,
+                                [s for _, _, s, _ in admits],
+                                skip_pages=[skip for *_, skip in admits]))
+                        dt = time.monotonic() - t0
+                        report.prefill_s += dt
+                        report.prefill_calls += 1
+                        # TTFT: arrival (run start — all requests arrive
+                        # together) to the end of this admission group's
+                        # prefill; the group's cost enters each member once
+                        ttft = time.monotonic() - t_start
+                        for j, (r, prompt, s, _) in enumerate(admits):
+                            tok = self._pick_one(logits[j], r.rid, 0, key)
+                            stats = RequestStats(rid=r.rid,
+                                                 prompt_len=prompt.shape[0],
+                                                 tokens=[tok],
+                                                 admitted_step=step,
+                                                 slot=s, group=group,
+                                                 prefill_s=dt, ttft_s=ttft,
+                                                 retries=retries_by_rid.get(
+                                                     r.rid, 0))
+                            tr.metrics.observe("serve/ttft_s", ttft)
+                            slot = _Slot(r, stats, self._limit(r),
+                                         next_pos=prompt.shape[0],
+                                         last_tok=tok, t_admit=t0,
+                                         prompt=prompt)
+                            if len(stats.tokens) >= slot.limit:
+                                retire(s, slot)
+                            else:
+                                active[s] = slot
+                # ---- injected slot faults fire at their decode step ---------
+                if injector is not None and step not in faulted_steps:
+                    # consulted once per step value: a recovery that empties
+                    # the batch loops back here without re-firing the fault
+                    faulted_steps.add(step)
+                    inject_slot_faults()
+                if not active:
+                    continue
+                # ---- one batched decode step over every active slot ---------
+                toks = np.zeros((B, 1), np.int32)
+                pos = np.zeros(B, np.int32)
+                for s, slot in active.items():
+                    toks[s, 0] = slot.last_tok
+                    pos[s] = slot.next_pos
+                t0 = time.monotonic()
+                with tr.span("sched", "decode_step", step=step,
+                             slots=len(active), pages=store.pages_in_use):
+                    logits, _ = eng.decode(toks, store, pos)
+                    logits = np.asarray(logits)
+                report.decode_s += time.monotonic() - t0
+                report.decode_steps += 1
+                report.slot_steps += len(active)
+                report.page_steps += store.pages_in_use
+                tr.counter("sched", "active_slots", len(active))
+                tr.counter("sched", "pages_in_use", store.pages_in_use)
+                step += 1
+                # ---- advance / retire --------------------------------------
+                for s in sorted(active):
+                    slot = active[s]
+                    tok = self._pick_one(logits[s], slot.req.rid,
+                                         len(slot.stats.tokens), key)
+                    slot.stats.tokens.append(tok)
+                    slot.next_pos += 1
+                    slot.last_tok = tok
+                    if len(slot.stats.tokens) >= slot.limit:
+                        del active[s]
+                        retire(s, slot)
+                if callback is not None:
+                    try:
+                        callback(step, len(active))
+                    except StopServing:
+                        # abort: the replica died — report only what retired;
+                        # the Router replays the rest on the survivors
+                        report.aborted_step = step
+                        break
         report.wall_s = time.monotonic() - t_start
         report.peak_pages = store.peak_pages
         report.prefix_hit_tokens = mm.prefix_hit_tokens - base[0]

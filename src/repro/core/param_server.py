@@ -37,6 +37,33 @@ def tree_flatten_np(tree):
     return [np.asarray(l) for l in leaves], treedef
 
 
+#: shorter waits for a lock are not recorded (taking a free lock and
+#: reading the clock twice costs about a microsecond)
+LOCK_WAIT_MIN_S = 1e-5
+
+
+class _TimedLock:
+    """Takes a lock and records the wait, if long, as a `ps/lock_wait`
+    span (after the fact, like the workers' gate waits)."""
+
+    __slots__ = ("_lock", "_tr", "_kind")
+
+    def __init__(self, lock, tracer, kind: str):
+        self._lock, self._tr, self._kind = lock, tracer, kind
+
+    def __enter__(self):
+        t0 = self._tr.now()
+        self._lock.acquire()
+        t1 = self._tr.now()
+        if t1 - t0 > LOCK_WAIT_MIN_S:
+            self._tr.add_span("ps", "lock_wait", t0, t1, lock=self._kind)
+        return self
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        return False
+
+
 @dataclass
 class PendingPush:
     """A push whose wire transfer has been issued but not yet applied."""
@@ -94,6 +121,13 @@ class ParameterServer:
         self.transport = transport if transport is not None \
             else NullTransport()
 
+    def _locked(self, lock, kind: str):
+        """`lock` as a context manager; with tracing on, a wait longer than
+        LOCK_WAIT_MIN_S to take it is recorded as a `ps/lock_wait` span."""
+        if not self.tracer.enabled:
+            return lock
+        return _TimedLock(lock, self.tracer, kind)
+
     # -- worker lifecycle -------------------------------------------------
     def register(self, wid: str):
         self.clock.register(wid)
@@ -107,18 +141,19 @@ class ParameterServer:
         """Compress a wave-aggregated delta and start its wire transfer.
         Does not block on the (simulated) network and does not touch
         w_global; the caller finishes with finish_push."""
-        leaves, _ = tree_flatten_np(deltas_tree)
         updates, wire, dense = [], 0, 0
-        for i, d in enumerate(leaves):
-            flat = d.astype(np.float32).ravel()
-            dense += flat.nbytes
-            if self.compressor is not None:
-                idx, vals = self.compressor.compress(f"{wid}/{i}", flat)
-                wire += self.compressor.wire_bytes(idx, vals)
-                updates.append((i, idx, vals))
-            else:
-                wire += flat.nbytes
-                updates.append((i, None, flat))
+        with self.tracer.span("ps", "push_flatten", wid=wid):
+            leaves, _ = tree_flatten_np(deltas_tree)
+            for i, d in enumerate(leaves):
+                flat = d.astype(np.float32).ravel()
+                dense += flat.nbytes
+                if self.compressor is not None:
+                    idx, vals = self.compressor.compress(f"{wid}/{i}", flat)
+                    wire += self.compressor.wire_bytes(idx, vals)
+                    updates.append((i, idx, vals))
+                else:
+                    wire += flat.nbytes
+                    updates.append((i, None, flat))
         send = self.transport.send_async(wid, "ps", wire)
         with self._stats_lock:
             self.bytes_pushed += dense
@@ -149,8 +184,9 @@ class ParameterServer:
         by_shard: dict[int, list] = {}
         for upd in pending.updates:
             by_shard.setdefault(self.shard_of_leaf[upd[0]], []).append(upd)
-        with self.tracer.span("ps", "push_apply", wid=pending.wid,
-                              shards=len(by_shard)), self._snapshot_lock:
+        with (self.tracer.span("ps", "push_apply", wid=pending.wid,
+                               shards=len(by_shard)),
+              self._locked(self._snapshot_lock, "snapshot")):
             if self.injector is not None:
                 # push_count is stable under the snapshot lock, so which
                 # push a PSStall lands on is deterministic
@@ -163,7 +199,7 @@ class ParameterServer:
                     import time
                     time.sleep(stall)
             for sid, ups in by_shard.items():
-                with self._locks[sid]:
+                with self._locked(self._locks[sid], "shard"):
                     for i, idx, vals in ups:
                         if idx is None:
                             self.flat[i] += vals
@@ -222,7 +258,7 @@ class ParameterServer:
             hits = 0
             for i, f in enumerate(self.flat):
                 sid = self.shard_of_leaf[i]
-                with self._locks[sid]:
+                with self._locked(self._locks[sid], "shard"):
                     ver = self._shard_version[sid]
                     cached = self._leaf_cache[i]
                     if cached is not None and cached[0] == ver:

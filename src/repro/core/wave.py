@@ -30,6 +30,7 @@ from repro.configs.base import ArchConfig, RunConfig
 from repro.models import lm
 from repro.models.blocks import LayerCtx, apply_layer
 from repro.models.layers import chunked_cross_entropy
+from repro.obs import current_tracer
 from repro.optim import make_optimizer
 from repro.serve import cache as cache_lib
 
@@ -476,9 +477,24 @@ def build_local_wave_step(cfg: ArchConfig, nm: int, optimizer):
     lock = threading.Lock()
 
     def locked_step(params, opt_state, inputs, labels):
-        with lock:
-            deltas, opt_state, loss = wave_step(params, opt_state, inputs,
-                                                labels)
-            return jax.device_get(deltas), opt_state, loss
+        # the caller's tracer (the worker's `wave` span), in four spans:
+        # the wait for the device, the call (its numpy arguments go to the
+        # device), the device's work (waited for only when traced) and
+        # the deltas' copy to the host
+        tr = current_tracer()
+        with tr.span("wave_step", "lock_wait"):
+            lock.acquire()
+        try:
+            with tr.span("wave_step", "to_device"):
+                deltas, opt_state, loss = wave_step(params, opt_state,
+                                                    inputs, labels)
+            if tr.enabled:
+                with tr.span("wave_step", "compute"):
+                    jax.block_until_ready(deltas)
+            with tr.span("wave_step", "to_host"):
+                deltas = jax.device_get(deltas)
+        finally:
+            lock.release()
+        return deltas, opt_state, loss
 
     return locked_step

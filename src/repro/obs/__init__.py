@@ -18,12 +18,17 @@ Everything accepts a disabled tracer (the NULL_TRACER singleton) and then
 records nothing and allocates nothing on the hot path — instrumented code
 never needs a None check, and an untraced run is bit-identical to a traced
 one (tracing observes timing, never the data path).
+
+Enabled spans also enter `jax.profiler.TraceAnnotation("<track>/<name>")`,
+so a profiler trace names the host's time by the program's own layers;
+spans nested on one thread record their `parent`, and `current_tracer()`
+gives code without a tracer parameter the enclosing span's tracer.
 """
 from repro.obs.metrics import Histogram, MetricsRegistry
 from repro.obs.trace import (NULL_SPAN, NULL_TRACER, Tracer,
-                             emit_pipeline_ticks)
+                             current_tracer, emit_pipeline_ticks)
 
 __all__ = [
     "Histogram", "MetricsRegistry", "NULL_SPAN", "NULL_TRACER", "Tracer",
-    "emit_pipeline_ticks",
+    "current_tracer", "emit_pipeline_ticks",
 ]

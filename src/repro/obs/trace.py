@@ -15,6 +15,14 @@ returns the shared `NULL_SPAN` singleton from span() and falls through
 every other method without allocating or locking, so instrumentation can
 stay unconditionally in hot paths. The attached MetricsRegistry shares the
 enabled flag.
+
+An enabled span is also a `jax.profiler.TraceAnnotation` named
+`<track>/<name>` over the same interval, so a profiler trace taken
+meanwhile shows the program's spans on its `/host:CPU` plane, on the clock
+it shares with the device planes. Each thread keeps the stack of its open
+spans: a span opened inside another records `parent="<track>/<name>"` of
+the enclosing one in its args, and `current_tracer()` hands code that has
+no tracer parameter the tracer of the innermost open span.
 """
 from __future__ import annotations
 
@@ -40,20 +48,55 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_tr", "_track", "_name", "_args", "_t0")
+class _OpenSpans(threading.local):
+    """Per thread: the stack of open spans, innermost last."""
 
-    def __init__(self, tr: "Tracer", track: str, name: str, args: dict):
-        self._tr, self._track, self._name, self._args = tr, track, name, args
+    def __init__(self):
+        self.stack = []
+
+
+_open = _OpenSpans()
+
+
+class _Span:
+    __slots__ = ("_tr", "_owner", "_track", "_name", "_args", "_t0",
+                 "_ann", "label")
+
+    def __init__(self, tr: "Tracer", owner: "Tracer", track: str, name: str,
+                 args: dict):
+        # tr records the event; owner is the tracer (or scoped view) that
+        # opened the span, which current_tracer() hands out
+        self._tr, self._owner = tr, owner
+        self._track, self._name, self._args = track, name, args
+        self.label = f"{track}/{name}"
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        spans = _open.stack
+        if spans:
+            self._args["parent"] = spans[-1].label
+        spans.append(self)
+        self._ann = TraceAnnotation(self.label)
+        self._ann.__enter__()
         self._t0 = self._tr.now()
         return self
 
     def __exit__(self, *exc):
-        self._tr.add_span(self._track, self._name, self._t0, self._tr.now(),
+        t1 = self._tr.now()
+        self._ann.__exit__(*exc)
+        _open.stack.remove(self)
+        self._tr.add_span(self._track, self._name, self._t0, t1,
                           **self._args)
         return False
+
+
+def current_tracer() -> "Tracer":
+    """The tracer that opened the innermost span still open on this thread
+    (a scoped view, where a view opened it), or NULL_TRACER where none is:
+    code called inside a traced region records into the caller's tracer
+    without taking one as a parameter."""
+    spans = _open.stack
+    return spans[-1]._owner if spans else NULL_TRACER
 
 
 class Tracer:
@@ -77,14 +120,17 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
     def span(self, track: str, name: str, **args):
-        """Context manager timing a region onto `track`."""
+        """Context manager timing a region onto `track`, mirrored into the
+        profiler's trace as the annotation `<track>/<name>`."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, track, name, args)
+        return _Span(self, self, track, name, args)
 
     def add_span(self, track: str, name: str, t0: float, t1: float,
                  **args) -> None:
-        """Record an already-timed [t0, t1) interval."""
+        """Record an already-timed [t0, t1) interval. It is not mirrored
+        into the profiler's trace (its time has passed) and takes no
+        `parent`."""
         if not self.enabled:
             return
         with self._lock:
@@ -162,7 +208,7 @@ class _ScopedTracer(Tracer):
         return self._parent.now()
 
     def span(self, track: str, name: str, **args):
-        return self._parent.span(self._prefix + track, name, **args)
+        return _Span(self._parent, self, self._prefix + track, name, args)
 
     def add_span(self, track: str, name: str, t0: float, t1: float,
                  **args) -> None:

@@ -206,8 +206,6 @@ class VirtualWorker(threading.Thread):
                                    bounds=INT_BOUNDS)
                 tr.counter(self.wid, "staleness", stale)
                 if self.audit_D is not None and stale > self.audit_D:
-                    tr.instant(self.wid, "staleness_violation",
-                               wave=wave, stale=stale, D=self.audit_D)
                     tr.metrics.counter_inc("wsp/staleness_violations")
                 t0 = time.monotonic()
                 with tr.span(self.wid, "wave", wave=wave):

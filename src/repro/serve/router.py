@@ -354,10 +354,6 @@ class Router:
             if via != "score":
                 self._counters["affinity_hits"] += 1
                 tr.metrics.counter_inc("serve/router_affinity_hits")
-                tr.instant("router", "affinity_hit", rid=r.rid,
-                           replica=chosen, via=via)
-            tr.instant("router", "dispatch", rid=r.rid, replica=chosen,
-                       policy=self.policy, queue_depth=booked_depth[chosen])
         return assign
 
     # ------------------------------------------------------------------
@@ -388,7 +384,6 @@ class Router:
             depth = len(pending)
             self._counters["queue_depth_peak"] = max(
                 self._counters["queue_depth_peak"], depth)
-            tr.counter("router", "queue_depth", depth)
             if self._counters["rounds"]:
                 # everything here survived a replica death: requeued
                 self._counters["rebalances"] += depth
@@ -417,8 +412,6 @@ class Router:
                     self.replicas[i].down = True
                     self._down_at.pop(i, None)
                     self._counters["replica_downs"] += 1
-                    tr.instant("router", "replica_down", replica=i,
-                               step=rep_report.aborted_step)
                     tr.metrics.counter_inc("fault/replica_downs")
             survivors = [r for r in pending if r.rid not in done]
             if len(survivors) == len(pending):

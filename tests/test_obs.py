@@ -1,7 +1,9 @@
 """The observability layer (repro.obs): tracer/metrics semantics, the
 zero-overhead disabled path, Chrome-trace export schema, the WSP staleness
 audit, scheduler event invariants and Telemetry report plumbing."""
+import glob
 import json
+import threading
 import time
 
 import numpy as np
@@ -12,8 +14,10 @@ from repro.api import (BSP, ClusterSpec, Engine, Plan, RunSpec, ServeReport,
 from repro.api.serving import Request, Scheduler
 from repro.configs import ARCHS, reduced
 from repro.core.wave import tick_schedule
+from repro.core.param_server import ParameterServer
 from repro.obs import (NULL_SPAN, NULL_TRACER, Histogram, MetricsRegistry,
-                       Tracer, emit_pipeline_ticks)
+                       Tracer, current_tracer, emit_pipeline_ticks)
+from repro.obs import trace as trace_mod
 from repro.obs.export import load, to_chrome, validate_chrome, write_chrome
 from repro.obs.metrics import INT_BOUNDS, quantile_from_snapshot
 from repro.obs.summary import main as summary_main, summarize
@@ -101,10 +105,12 @@ def test_disabled_tracer_hot_path_overhead():
     t0 = time.monotonic()
     for _ in range(100_000):
         with tr.span("track", "name", k=1):
-            pass
+            with current_tracer().span("track", "inner"):
+                pass
         tr.counter("track", "c", 1)
     assert time.monotonic() - t0 < 1.0
     assert len(tr) == 0
+    assert current_tracer() is NULL_TRACER
 
 
 def test_tracer_records_span_instant_counter():
@@ -125,6 +131,119 @@ def test_tracer_records_span_instant_counter():
     assert (track, name, t0, dur, args) == ("trk", "work", 1.0, 1.0,
                                             {"tag": "x"})
     assert evs[2][5] == {"depth": 4}
+
+
+# ---------------------------------------------------------------------------
+# the enclosing span: parent, current_tracer(), the profiler's clock
+# ---------------------------------------------------------------------------
+def test_nested_spans_record_their_parent():
+    tr = Tracer()
+    with tr.span("a", "outer", k=1):
+        with tr.span("b", "inner"):
+            pass
+        with tr.scoped("r0/").span("c", "leaf"):
+            pass
+    with tr.span("a", "again"):
+        pass
+    by = {e[2]: e[5] for e in tr.events()}
+    assert by["outer"] == {"k": 1} and by["again"] == {}
+    assert by["inner"] == {"parent": "a/outer"}
+    assert by["leaf"] == {"parent": "a/outer"}
+    with tr.scoped("r1/").span("sched", "step"):
+        with tr.scoped("r1/").span("sched", "decode_step"):
+            pass
+    assert tr.events()[-2][5] == {"parent": "r1/sched/step"}
+
+
+def test_current_tracer_is_the_enclosing_spans_per_thread():
+    tr = Tracer()
+    view = tr.scoped("r0/")
+    seen = {}
+
+    def other():
+        seen["other"] = current_tracer()
+
+    assert current_tracer() is NULL_TRACER
+    with tr.span("t", "outer"):
+        assert current_tracer() is tr
+        with view.span("t", "inner"):
+            assert current_tracer() is view    # the view keeps its prefix
+            with current_tracer().span("t", "leaf"):
+                pass
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+        assert current_tracer() is tr
+    assert current_tracer() is NULL_TRACER
+    assert seen["other"] is NULL_TRACER
+    leaf = [e for e in tr.events() if e[2] == "leaf"][0]
+    assert leaf[1] == "r0/t" and leaf[5] == {"parent": "r0/t/inner"}
+
+
+def test_disabled_tracer_grows_no_stack():
+    tr = Tracer(enabled=False)
+    with tr.span("t", "x"):
+        assert current_tracer() is NULL_TRACER
+        assert trace_mod._open.stack == []
+        with Tracer().span("t", "y"):
+            # an enabled span inside a disabled one has no parent
+            assert len(trace_mod._open.stack) == 1
+    assert trace_mod._open.stack == []
+    assert len(tr) == 0
+
+
+def test_enabled_span_is_on_the_profilers_host_plane(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("probe_track", "probe_span"):
+            time.sleep(0.02)
+        tr.add_span("probe_track", "after_the_fact", 0.0, 1.0)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [e for plane in ProfileData.from_file(path[0]).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith("probe_track")]
+    assert [e.name for e in host] == ["probe_track/probe_span"]
+    dur = [e[4] for e in tr.events() if e[2] == "probe_span"][0]
+    assert abs(host[0].duration_ns * 1e-9 - dur) < 1e-3
+
+
+def test_ps_lock_waits_are_recorded_only_when_traced():
+    import numpy as np
+    tree = {"a": np.zeros(4, np.float32), "b": np.ones(3, np.float32)}
+    plain = ParameterServer(tree, num_shards=2)
+    lock = plain._locks[0]
+    assert plain._locked(lock, "shard") is lock     # untraced: the lock
+    tr = Tracer()
+    ps = ParameterServer(tree, num_shards=2, tracer=tr)
+    held = threading.Event()
+
+    def hold():
+        with ps._locks[0]:
+            held.set()
+            time.sleep(0.05)
+
+    th = threading.Thread(target=hold)
+    th.start()
+    held.wait(10)
+    ps.pull("vw0")
+    th.join(timeout=10)
+    assert not th.is_alive()
+    waits = [e for e in tr.events() if e[1:3] == ("ps", "lock_wait")]
+    assert len(waits) == 1
+    assert waits[0][5] == {"lock": "shard"} and waits[0][4] > 0.02
+    ps.push_wave("vw0", {"a": np.ones(4, np.float32),
+                         "b": np.ones(3, np.float32)})
+    # nobody held a lock: no wait long enough to record
+    assert len([e for e in tr.events() if e[2] == "lock_wait"]) == 1
+    flat = [e for e in tr.events() if e[1:3] == ("ps", "push_flatten")]
+    assert len(flat) == 1 and flat[0][5] == {"wid": "vw0"}
 
 
 # ---------------------------------------------------------------------------
@@ -397,3 +516,80 @@ def test_telemetry_helpers():
     assert tel.to_dict()["gauges"]["link/eth/bytes"] == 1e6
     assert Telemetry().staleness_max() is None
     assert Telemetry().bubble_fraction() is None
+
+
+# ---------------------------------------------------------------------------
+# spans where the work happens: the locked wave step, the parameter server,
+# the Scheduler's loop, the Engine's decode dispatch
+# ---------------------------------------------------------------------------
+#: the benchmark harness's own profiler annotations (bench/trace_reduce.py's
+#: ANNOTATIONS); a program span named like one would move its idle labels
+HARNESS_ANNOTATIONS = {"engine.prefill_into", "engine.decode",
+                       "logits_to_host", "wave_step", "ps.pull",
+                       "ps.push_wave", "loader.next"}
+
+
+@pytest.fixture(scope="module")
+def traced_fit_events():
+    tr = Tracer()
+    rep = Engine(_wsp_plan(), tracer=tr).fit()
+    return tr.events(), rep
+
+
+@pytest.fixture(scope="module")
+def traced_sched_events():
+    tr = Tracer()
+    rep, _ = _sched_run(tracer=tr)
+    return tr.events(), rep
+
+
+def test_threaded_fit_spans_every_wave(traced_fit_events):
+    evs, rep = traced_fit_events
+    waves = [e for e in evs if e[0] == "X" and e[2] == "wave"]
+    assert len(waves) == sum(len(v) for v in
+                             rep.losses_by_worker().values()) > 0
+    parts = ("lock_wait", "to_device", "compute", "to_host")
+    for part in parts:
+        spans = [e for e in evs if e[1:3] == ("wave_step", part)]
+        assert len(spans) == len(waves), part
+        assert {e[5]["parent"] for e in spans} == {"vw0/wave", "vw1/wave"}
+    # within each wave span the four parts run in order
+    for w in waves:
+        inside = sorted((e[3], e[2]) for e in evs if e[1] == "wave_step"
+                        and w[3] <= e[3] <= w[3] + w[4]
+                        and e[5]["parent"] == f"{w[1]}/wave")
+        assert [n for _, n in inside] == list(parts)
+    flat = [e for e in evs if e[1:3] == ("ps", "push_flatten")]
+    pushes = [e for e in evs if e[0] == "X" and e[2] == "push"]
+    assert len(flat) == len(pushes) == len(waves)
+    assert {e[5]["parent"] for e in flat} == {"vw0/push", "vw1/push"}
+    apply = [e for e in evs if e[1:3] == ("ps", "push_apply")]
+    assert {e[5]["parent"] for e in apply} == {"vw0/push", "vw1/push"}
+
+
+def test_sched_step_spans_hold_their_children(traced_sched_events):
+    evs, rep = traced_sched_events
+    steps = [e for e in evs if e[1:3] == ("sched", "step")]
+    kids = [e for e in evs
+            if e[0] == "X" and e[2] in ("prefill_group", "decode_step")]
+    assert len(kids) == rep.prefill_calls + rep.decode_steps
+    assert len(steps) >= rep.decode_steps
+    for k in kids:
+        assert k[5]["parent"] == "sched/step"
+        assert any(s[3] <= k[3] and k[3] + k[4] <= s[3] + s[4]
+                   for s in steps)
+    dispatch = [e for e in evs if e[1:3] == ("engine", "decode_dispatch")]
+    decode = [e for e in evs if e[1:3] == ("engine", "decode")]
+    assert len(dispatch) == len(decode) == rep.decode_steps
+    assert {e[5]["parent"] for e in dispatch} == {"engine/decode"}
+    assert {e[5]["parent"] for e in decode} == {"sched/decode_step"}
+
+
+def test_no_program_span_is_named_like_a_harness_annotation(
+        traced_fit_events, traced_sched_events):
+    labels = {f"{e[1]}/{e[2]}"
+              for evs, _ in (traced_fit_events, traced_sched_events)
+              for e in evs if e[0] == "X"}
+    assert {"vw0/push", "ps/push_apply", "wave_step/to_host", "sched/step",
+            "engine/decode_dispatch"} <= labels
+    assert not labels & HARNESS_ANNOTATIONS
