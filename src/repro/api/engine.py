@@ -418,7 +418,8 @@ class Engine:
     def _ensure_serve_store(self):
         """Build the paged (CacheStore-backed) serve executors: a variable-
         length prefill that scatters K/V pages through the block table, and
-        a per-row-position decode over the paged tree. Compiled separately
+        a per-row-position decode over the paged tree that takes the page
+        pool donated and writes it in place. Compiled separately
         from the aligned generate() path (which keeps the contiguous
         reference layout)."""
         if getattr(self, "_serve_paged", None) is not None:
@@ -436,7 +437,7 @@ class Engine:
             pre_fn, dec_fn = _ref_paged_steps(cfg, sv.kernel_backend)
             self._serve_paged = {"layout": layout, "shardings": None,
                                  "prefill": jax.jit(pre_fn),
-                                 "decode": jax.jit(dec_fn)}
+                                 "decode": _PoolDonatingDecode(dec_fn)}
             return
 
         from repro.configs.base import RunConfig, ShapeConfig
@@ -475,7 +476,7 @@ class Engine:
 
         self._serve_paged = {"layout": layout, "shardings": shardings,
                              "prefill": jax.jit(pre_fn),
-                             "decode": jax.jit(dec_fn)}
+                             "decode": _PoolDonatingDecode(dec_fn)}
 
     def serve_steps(self):
         """(prefill, decode, params): the jitted paged-executor steps the
@@ -1083,6 +1084,35 @@ def _ref_paged_steps(cfg, kernel_backend="ref"):
         return lm.logits_ref(cfg, params, hid), cache
 
     return pre_fn, dec_fn
+
+
+class _PoolDonatingDecode:
+    """The jitted paged decode step `dec_fn(params, tokens, cache, pos)`,
+    with the page pool `cache["kv_full"]` donated and nothing else: the
+    step writes each row's token into the pool in place and
+    CacheStore.update adopts the pool it returns, while the block table
+    and the per-slot leaves stay the caller's. Called and lowered with
+    dec_fn's arguments; the program keeps dec_fn's name, which also names
+    its kernel's custom call."""
+
+    def __init__(self, dec_fn):
+        def step(params, tokens, pool, rest, pos):
+            cache = rest if pool is None else dict(rest, kv_full=pool)
+            return dec_fn(params, tokens, cache, pos)
+
+        step.__name__ = step.__qualname__ = dec_fn.__name__
+        self._jit = jax.jit(step, donate_argnums=(2,))
+
+    @staticmethod
+    def _split(cache):
+        rest = dict(cache)
+        return rest.pop("kv_full", None), rest
+
+    def __call__(self, params, tokens, cache, pos):
+        return self._jit(params, tokens, *self._split(cache), pos)
+
+    def lower(self, params, tokens, cache, pos):
+        return self._jit.lower(params, tokens, *self._split(cache), pos)
 
 
 def _pick(logits, temperature, key):

@@ -244,18 +244,29 @@ def page_view(pool, i, tab):
 
 
 def page_write_token(pool, i, tab, pos, new_row, sel):
-    """Decode-time single-token scatter: row b's token lands in the page
+    """Decode-time single-token write: row b's token lands in the page
     holding logical position pos[b]. pool [m, NP+1, KV, ps, hd]; tab
     [B, pps]; pos, sel [B]; new_row [B, 1, KV, hd]. Rows with sel False or
-    an unmapped page write to the trash page instead (never read)."""
+    an unmapped page write to the trash page instead (never read).
+
+    One dynamic_update_slice per row, not a scatter: a scatter of the
+    [KV, hd] window (dims 2 and 4, not adjacent) gets a layout of its own
+    from XLA, and the paged kernel's operand then relayouts the whole pool
+    back at every layer. Row writes keep the pool's layout, so a donated
+    pool is updated in place."""
     B, pps = tab.shape
     ps = pool.shape[3]
     trash = pool.shape[1] - 1
     lp = jnp.clip(pos // ps, 0, pps - 1)
-    off = jnp.clip(pos, 0, None) % ps
+    off = (jnp.clip(pos, 0, None) % ps).astype(jnp.int32)
     phys = tab[jnp.arange(B), lp]                       # [B]
-    phys = jnp.where(sel & (phys >= 0), phys, trash)
-    return pool.at[i, phys, :, off].set(new_row[:, 0].astype(pool.dtype))
+    phys = jnp.where(sel & (phys >= 0), phys, trash).astype(jnp.int32)
+    rows = new_row[:, 0, :, None, :].astype(pool.dtype)  # [B, KV, 1, hd]
+    i, zero = jnp.asarray(i, jnp.int32), jnp.int32(0)
+    for b in range(B):
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[b][None, None], (i, phys[b], zero, off[b], zero))
+    return pool
 
 
 def page_write_prompt(pool, i, tab, new_kv, sel, lens=None):

@@ -486,3 +486,148 @@ def test_page_write_token_routes_unmapped_to_trash():
     assert np.all(out[0, 2, :, 1] == 1.0)       # row 0: page 2, offset 1
     assert np.all(out[0, :lo.num_pages].sum() == 2.0)  # nothing else live
     assert np.all(out[0, lo.trash_page, :, 2] == 1.0)  # row 1 -> trash
+
+
+def _write_token_oracle(pool, i, tab, pos, row, sel):
+    """The decode write's semantics, one row at a time in numpy: row b's
+    token lands at (page of pos[b], offset pos[b] % ps) of group i, or at
+    that offset of the trash page when the row is unselected or its page
+    unmapped. Returns (pool, {trash offset: rows routed there})."""
+    out = np.array(pool)
+    B, pps = tab.shape
+    ps, trash = pool.shape[3], pool.shape[1] - 1
+    to_trash = {}
+    for b in range(B):
+        phys = tab[b, min(max(pos[b] // ps, 0), pps - 1)]
+        off = max(pos[b], 0) % ps
+        if not sel[b] or phys < 0:
+            to_trash.setdefault(off, []).append(b)
+            continue
+        out[i, phys, :, off] = row[b, 0]
+    return out, to_trash
+
+
+_WRITE_CASES = {
+    # name: (block table rows, positions, selected rows)
+    "mapped": ([[0, 1], [2, 3], [4, 5]], [0, 5, 7], [1, 1, 1]),
+    "unselected": ([[0, 1], [2, 3], [4, 5]], [3, 4, 6], [1, 0, 1]),
+    "unmapped": ([[0, -1], [-1, -1], [4, 5]], [6, 1, 3], [1, 1, 1]),
+    "several_to_trash": ([[0, 1], [-1, -1], [4, -1]], [2, 2, 6], [0, 1, 1]),
+    "page_edges": ([[5, 0], [1, 2], [3, 4]], [4, 3, 0], [1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_page_write_token_matches_scatter_oracle(case):
+    """The per-row decode write matches the scatter it replaced, on mapped
+    rows, unselected rows, unmapped pages, several rows sent to the trash
+    page, and offsets 0 and page_size - 1 (page size 4, group 1 of 2).
+    Only the trash page takes duplicate writes: each offset written there
+    holds one of the rows routed to it, and every other slot of the pool
+    equals the oracle's."""
+    tab_rows, pos_l, sel_l = _WRITE_CASES[case]
+    B, ps, KV, hd = 3, 4, 2, 3
+    lo = make_layout(B, 8, page_size=ps)
+    rng = np.random.default_rng(sorted(_WRITE_CASES).index(case))
+    pool = rng.standard_normal((2, lo.num_pages + 1, KV, ps, hd)).astype(
+        np.float32)
+    row = rng.standard_normal((B, 1, KV, hd)).astype(np.float32)
+    tab = np.asarray(tab_rows, np.int32)
+    pos = np.asarray(pos_l, np.int32)
+    sel = np.asarray(sel_l, bool)
+    got = np.asarray(cache_lib.page_write_token(
+        jnp.asarray(pool), 1, jnp.asarray(tab), jnp.asarray(pos),
+        jnp.asarray(row), jnp.asarray(sel)))
+    want, to_trash = _write_token_oracle(pool, 1, tab, pos, row, sel)
+    trash = lo.trash_page
+    np.testing.assert_array_equal(got[:, :trash], want[:, :trash])
+    np.testing.assert_array_equal(got[0], want[0])
+    for off in range(ps):
+        held = got[1, trash, :, off]
+        if off not in to_trash:
+            np.testing.assert_array_equal(held, pool[1, trash, :, off])
+        else:
+            assert any(np.array_equal(held, row[b, 0])
+                       for b in to_trash[off]), (off, to_trash[off])
+
+
+@pytest.mark.parametrize("kb", ["ref", "interpret"])
+def test_donated_pool_survives_decode_copy_prefill_and_gather(kb):
+    """The paged decode step takes the page pool donated. Several decodes
+    on one CacheStore, then a copy-on-write page copy, a prefill into a
+    freed slot, a gathered view and one more decode: nothing reads a
+    deleted buffer, the copied page equals its source, the gathered K
+    equals the contiguous cache's, and every logit equals the contiguous
+    path's for the same sequences (the re-admitted row replays its first
+    decode step)."""
+    cfg = _cfg("qwen3-0.6b")
+    B, P, gen, ps = 3, 20, 6, 8
+    params, _ = lm.init_params(cfg, jax.random.PRNGKey(9))
+    prompts = np.random.default_rng(9).integers(
+        0, cfg.vocab_size, (B, P)).astype(np.int32)
+
+    def engine(page_size):
+        return Engine(Plan(arch=cfg, serve=ServeSpec(
+            prompt_len=P, gen=gen, max_batch=B, page_size=page_size,
+            kernel_backend=kb)), params=params)
+
+    contig, paged = engine(0), engine(ps)
+    want_pf, cache = contig.prefill(jnp.asarray(prompts))
+    store = paged.serve_store()
+    for s in range(B):
+        store.alloc(s, P + gen)
+    got_pf = paged.prefill_into(store, prompts, np.full(B, P), list(range(B)))
+    np.testing.assert_allclose(np.asarray(got_pf), np.asarray(want_pf),
+                               rtol=1e-5, atol=1e-5)
+    first = np.argmax(np.asarray(want_pf), axis=-1).astype(np.int32)
+    tok = first[:, None]
+    steps = 3
+    for t in range(steps):
+        want, cache = contig.decode(tok, cache, P + t)
+        held = store.tree["kv_full"]
+        got, _ = paged.decode(tok, store, np.full(B, P + t))
+        assert all(pool.is_deleted() for pool in held)   # donated
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        if t == 0:
+            first_step = np.asarray(want)
+        tok = np.argmax(np.asarray(want), axis=-1).astype(np.int32)[:, None]
+
+    # copy-on-write into a page slot 2 gave back
+    tab = np.asarray(store.tree["block_tab"])
+    src, dst = int(tab[0, 0]), int(tab[2, 0])
+    assert store.free(2) == []
+    store.copy_page(src, dst)
+    for pool in store.tree["kv_full"]:
+        np.testing.assert_array_equal(np.asarray(pool[:, dst]),
+                                      np.asarray(pool[:, src]))
+
+    # slot 2 re-admits its prompt; rows 0 and 1 keep their pages
+    store.alloc(2, P + gen)
+    rows = np.zeros((B, P), np.int32)
+    rows[0] = prompts[2]
+    lens = np.ones(B, np.int32)
+    lens[0] = P
+    re_pf = paged.prefill_into(store, rows, lens, [2])
+    np.testing.assert_allclose(np.asarray(re_pf[0]), np.asarray(want_pf[2]),
+                               rtol=1e-5, atol=1e-5)
+
+    k_view, v_view, gpos = store.gather_view(0)
+    depth = P + steps
+    for pool, view in zip(cache["kv_full"], (k_view, v_view)):
+        np.testing.assert_allclose(np.asarray(view[:2, :depth]),
+                                   np.asarray(pool[0, :2, :depth]),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(view[2, :P]),
+                                   np.asarray(pool[0, 2, :P]),
+                                   rtol=1e-6, atol=1e-6)
+    assert np.all(np.asarray(gpos)[:, :depth] >= 0)
+
+    tok[2, 0] = first[2]
+    pos = np.asarray([depth, depth, P], np.int32)
+    want, cache = contig.decode(tok, cache, pos)
+    got, _ = paged.decode(tok, store, pos)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[2]), first_step[2],
+                               rtol=1e-5, atol=1e-5)

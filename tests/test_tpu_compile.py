@@ -4,14 +4,17 @@ The TPU compiler is installed with jaxlib and compiles for a chip that is
 described, not attached, so these tests catch what interpret mode cannot: a
 block shape Mosaic refuses, an unsupported primitive, a kernel that asks
 for more VMEM than a core has. Shapes are the published widths of the
-configs the kernels serve. Nothing runs, so nothing here says anything
-about results or speed.
+configs the kernels serve. The paged decode step is compiled whole too, to
+check from its HLO that it updates the page pool in place. Nothing runs,
+so nothing here says anything about results or speed.
 
 The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,3 +123,35 @@ def test_ssd_chunked_hymba(one_chip, S):
     P = HYMBA.d_inner // H
     _compile(ssd_chunked, one_chip, ((1, H, S, P), F32), ((1, H, S), F32),
              ((1, S, N), F32), ((1, S, N), F32), ((H,), F32))
+
+
+def test_paged_decode_step_updates_pool_in_place(one_chip):
+    """The paged decode step, jitted as the Engine jits it, at qwen3-0.6b's
+    widths cut to 2 layers (16 slots, max_len 2560, 80 pages of 128, f32):
+    no copy produces a pool-shaped array, both donated pools alias outputs,
+    and the step's one Mosaic kernel per layer keeps the step's name."""
+    from repro.api.engine import _PoolDonatingDecode, _ref_paged_steps
+    from repro.models import lm
+    from repro.serve import cache as cache_lib
+    cfg = dataclasses.replace(QWEN, num_layers=2)
+    B = 16
+    layout = cache_lib.make_layout(B, 2560, page_size=128, max_pages=80)
+    tree, _ = cache_lib.paged_struct(cfg, layout, dtype=F32)
+    sds = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    tree = jax.tree.map(sds, tree)
+    params = jax.tree.map(sds, lm.param_shapes(cfg))
+    _, dec_fn = _ref_paged_steps(cfg, "tpu")
+    compiled = _PoolDonatingDecode(dec_fn).lower(
+        params, sds(jax.ShapeDtypeStruct((B, 1), I32)), tree,
+        sds(jax.ShapeDtypeStruct((B,), I32))).compile()
+    text = compiled.as_text()
+    pool = tree["kv_full"][0]
+    shape = "f32[" + ",".join(map(str, pool.shape)) + "]"
+    copies = re.findall(r"= " + re.escape(shape) + r"\{[^}]*\} copy\(",
+                        text)
+    assert not copies, f"{len(copies)} pool-sized copies in the decode step"
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+    kernels = re.findall(r"%(\S+) = .*custom-call\(.*tpu_custom_call", text)
+    assert len(kernels) == cfg.num_layers
+    assert all(k.startswith("dec_fn") for k in kernels), kernels
